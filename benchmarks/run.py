@@ -37,6 +37,7 @@ def main(argv=None) -> int:
 
     from repro.core import backend as backend_mod
     from repro.core.options import CompileOptions, use_options
+    from repro.launch import env
 
     if args.list_backends:
         for name in backend_mod.available_backends():
@@ -51,6 +52,8 @@ def main(argv=None) -> int:
             except backend_mod.UnknownBackendError as e:
                 p.error(str(e))
 
+    print(env.device_line())
+    env.enable_compile_cache()
     from benchmarks import (autotune_bench, batched_gemm_bench,
                             fusion_bench, gemm_bench, mala_bench,
                             resnet_bench, spmv_bench)

@@ -19,9 +19,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.core.backend import TPU_HIERARCHY
 
 
 def _small_kernel(a_ref, b_ref, o_ref):
@@ -77,8 +77,10 @@ def batched_gemm(a: jax.Array, b: jax.Array, *, batch_block: int = 8,
                       pl.BlockSpec((bb, k, n), lambda i: (i, 0, 0))],
             out_specs=pl.BlockSpec((bb, m, n), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((pb, m, n), a.dtype),
-            compiler_params=pallas_compat.CompilerParams(
-                dimension_semantics=("parallel",)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",),
+                vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+            name="batched_gemm",
             interpret=interpret,
         )(a, b)
         out = out[:bsz]
@@ -102,9 +104,11 @@ def batched_gemm(a: jax.Array, b: jax.Array, *, batch_block: int = 8,
                                    lambda bi, i, j, kk: (bi, i, j)),
             out_shape=jax.ShapeDtypeStruct((bsz, pm, pn), a.dtype),
             scratch_shapes=[pltpu.VMEM((1, bm_, bn_), jnp.float32)],
-            compiler_params=pallas_compat.CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
+                                     "arbitrary"),
+                vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+            name="batched_gemm",
             interpret=interpret,
         )(a, b)
         out = out[:, :m, :n]

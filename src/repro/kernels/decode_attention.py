@@ -5,7 +5,10 @@ whole (B, Hkv, S, hd) KV cache.  Grid = (B·Hkv, S/bs): each program
 handles one (batch row, kv head) pair; the GQA head group (rep = Hq/Hkv)
 rides the sublane axis so the q·K product is a (rep, bs) MXU matmul per
 block.  Running (m, l, acc) online-softmax state lives in VMEM scratch
-across the KV sweep; ``lengths`` masks the valid prefix per row.
+across the KV sweep; ``lengths`` masks the valid prefix per row.  The
+per-row lengths ride as a scalar-prefetch operand in SMEM (as the page
+table does in ``paged_kv.py``): a ``(1, 1)`` VMEM block of a
+``(B·Hkv, 1)`` array is not a tile Mosaic accepts.
 
 This is the kernel the decode_32k / long_500k cells would run on TPU —
 the XLA library path (ref.decode_attention) remains the CPU/dry-run
@@ -19,9 +22,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 NEG_INF = -1e30
 
@@ -37,7 +39,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    length = len_ref[0, 0]
+    length = len_ref[pl.program_id(0)]
 
     # skip KV blocks wholly past this row's valid prefix: with ragged
     # per-row lengths (continuous batching / paged slots) short rows
@@ -94,26 +96,29 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     qr = q.reshape(B, Hkv, rep, D).reshape(B * Hkv, rep, D)
     kr = k_cache.reshape(B * Hkv, ps, D)
     vr = v_cache.reshape(B * Hkv, ps, D)
-    len_r = jnp.repeat(lengths.astype(jnp.int32), Hkv).reshape(
-        B * Hkv, 1)
+    len_r = jnp.repeat(lengths.astype(jnp.int32), Hkv)
     grid = (B * Hkv, ps // bs)
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, bs=bs, kv_steps=grid[1],
-                          scale=scale, window=window),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda h, j: (h, 0)),
-            pl.BlockSpec((1, rep, D), lambda h, j: (h, 0, 0)),
-            pl.BlockSpec((1, bs, D), lambda h, j: (h, j, 0)),
-            pl.BlockSpec((1, bs, D), lambda h, j: (h, j, 0)),
+            pl.BlockSpec((1, rep, D), lambda h, j, len_ref: (h, 0, 0)),
+            pl.BlockSpec((1, bs, D), lambda h, j, len_ref: (h, j, 0)),
+            pl.BlockSpec((1, bs, D), lambda h, j, len_ref: (h, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rep, D), lambda h, j: (h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, rep, D), q.dtype),
+        out_specs=pl.BlockSpec((1, rep, D), lambda h, j, len_ref: (h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rep, 1), jnp.float32),
                         pltpu.VMEM((rep, 1), jnp.float32),
                         pltpu.VMEM((rep, D), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, bs=bs, kv_steps=grid[1],
+                          scale=scale, window=window),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, rep, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(len_r, qr, kr, vr)
     return out.reshape(B, Hq, D)

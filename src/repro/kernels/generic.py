@@ -15,9 +15,9 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.core.backend import TPU_HIERARCHY
 
 
 def _ceil(a: int, b: int) -> int:
@@ -53,8 +53,10 @@ def block_map(fn: Callable, args: Sequence[jax.Array], out_shape: tuple,
         in_specs=[pl.BlockSpec(block, idx_map) for _ in padded_args],
         out_specs=pl.BlockSpec(block, idx_map),
         out_shape=jax.ShapeDtypeStruct(padded, out_dtype),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel",) * len(grid)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",) * len(grid),
+            vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+        name="block_map",
         interpret=interpret,
     )(*padded_args)
     if padded != tuple(out_shape):

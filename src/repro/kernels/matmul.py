@@ -13,9 +13,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
+from repro.core.backend import TPU_HIERARCHY
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
@@ -64,8 +64,10 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int = 128, bn: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+        name="matmul",
         interpret=interpret,
     )(a, b)
     return out[:m, :n] if (pm, pn) != (m, n) else out

@@ -141,6 +141,7 @@ def page_gather_pallas(pool, table, lengths, *, block_size,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
             (n_slots, heads, blocks_per_slot * bs, hd), pool.dtype),
+        name="page_gather",
         interpret=interpret,
     )(table.astype(jnp.int32), pool)
 

@@ -14,9 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 RGLRU_C = 8.0
 
@@ -78,8 +77,9 @@ def rglru_scan(x: jax.Array, r_gate: jax.Array, i_gate: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, d_block), lambda b, d, t: (b, t, d)),
         out_shape=jax.ShapeDtypeStruct((B, pt, pd), x.dtype),
         scratch_shapes=[pltpu.VMEM((1, d_block), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="rglru_scan",
         interpret=interpret,
     )(xp, rp, ip, lap)
     return out[:, :T, :D]

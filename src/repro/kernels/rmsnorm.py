@@ -8,9 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import pallas_compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -43,8 +41,9 @@ def rmsnorm(x: jax.Array, weight: jax.Array, *, eps: float = 1e-6,
                   pl.BlockSpec((1, D), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pr, D), x.dtype),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="rmsnorm",
         interpret=interpret,
     )(x2, weight[None, :])
     return out[:R].reshape(orig_shape)

@@ -17,9 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-from repro.kernels import pallas_compat
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
@@ -88,8 +86,9 @@ def rwkv6_scan(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((1, chunk, V), lambda h, t: (h, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, pt, V), v.dtype),
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="rwkv6_scan",
         interpret=interpret,
     )(rp, kp, vp, wp, u_full)
     out = out[:, :T, :].reshape(B, H, T, V).transpose(0, 2, 1, 3)

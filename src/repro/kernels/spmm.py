@@ -15,9 +15,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
-from repro.kernels.spmv import EllMatrix, _ceil, as_ell
+from repro.core.backend import TPU_HIERARCHY
+from repro.kernels.spmv import EllMatrix, _ceil, as_ell, lane_block
 
 
 def _spmm_kernel(vals_ref, bg_ref, o_ref):
@@ -42,7 +43,7 @@ def spmm_ell(ell: EllMatrix, b: jax.Array, *, row_block: int = 128,
     b_g = jnp.where(ell.valid[:, :, None], b[ell.indices], 0.0) \
         .astype(jnp.float32)
     rb = min(row_block, max(n_rows, 1))
-    rw = min(row_width, width)
+    rw = lane_block(row_width, width)
     cb = min(col_block, n)
     pr = _ceil(n_rows, rb) * rb
     pw = _ceil(width, rw) * rw
@@ -61,8 +62,10 @@ def spmm_ell(ell: EllMatrix, b: jax.Array, *, row_block: int = 128,
                   pl.BlockSpec((rb, rw, cb), lambda i, j, s: (i, s, j))],
         out_specs=pl.BlockSpec((rb, cb), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pr, pn), b.dtype),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+        name="spmm_ell",
         interpret=interpret,
     )(vals, b_g)
     return out[:n_rows, :n]

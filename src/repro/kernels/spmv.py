@@ -25,10 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.backend import TPU_HIERARCHY
 from repro.core.ir import ell_storage_width
-from repro.kernels import pallas_compat
 
 
 class CsrMatrix(NamedTuple):
@@ -53,6 +53,17 @@ class EllMatrix(NamedTuple):
 
 def _ceil(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def lane_block(row_width: int, width: int) -> int:
+    """The ELL column-slab width a kernel block may take.  Mosaic needs a
+    block's last dim to be a multiple of the 128-lane tile or the whole
+    array dim, so the tiling's ``row_width`` (the paper's vector length,
+    often 8 or 16) is rounded up to whole lanes, and a slab that would
+    cover the full ELL width becomes exactly that width."""
+    lanes = TPU_HIERARCHY.vector_width
+    rw = _ceil(max(row_width, 1), lanes) * lanes
+    return width if rw >= width else rw
 
 
 def csr_to_ell(indptr, indices, values, n_rows: int, n_cols: int,
@@ -110,7 +121,7 @@ def spmv_ell(ell: EllMatrix, x: jax.Array, *, row_block: int = 256,
         return jnp.zeros((0,), x.dtype)   # no rows: never launch a 0-grid
     x_g = jnp.where(ell.valid, x[ell.indices], 0.0).astype(jnp.float32)
     rb = min(row_block, max(n_rows, 1))
-    rw = min(row_width, width)
+    rw = lane_block(row_width, width)
     pr = _ceil(n_rows, rb) * rb
     pw = _ceil(width, rw) * rw
     vals = ell.values
@@ -125,8 +136,10 @@ def spmv_ell(ell: EllMatrix, x: jax.Array, *, row_block: int = 256,
                   pl.BlockSpec((rb, rw), lambda i, s: (i, s))],
         out_specs=pl.BlockSpec((rb, 1), lambda i, s: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pr, 1), x.dtype),
-        compiler_params=pallas_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_HIERARCHY.scratch_bytes),
+        name="spmv_ell",
         interpret=interpret,
     )(vals, x_g)
     return out[:n_rows, 0]

@@ -381,3 +381,18 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
     d = dict(r["collectives"])
     d["counts"] = r["collective_counts"]
     return d
+
+
+def kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """Pallas kernels that compiled to Mosaic custom calls, counted by
+    kernel name.  Every kernel's ``pallas_call`` carries a ``name=``,
+    which lands in the op_name metadata as ``.../<name>/pallas_call``; a
+    kernel run in interpret mode, or an op that took the library path,
+    leaves no ``tpu_custom_call`` at all."""
+    counts: Dict[str, int] = defaultdict(int)
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        m = re.search(r'op_name="[^"]*?(\w+)/pallas_call', line)
+        counts[m.group(1) if m else "?"] += 1
+    return dict(counts)

@@ -55,6 +55,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core import ops as cops
 from repro.core.options import CompileOptions, use_options
+from repro.launch import env
 from repro.launch import steps as steps_mod
 from repro.models import serve as serve_mod
 from repro.models.model import build_model
@@ -243,6 +244,7 @@ def serve_paged(model, params, requests: Sequence[Request], *,
                 policy: str = "continuous",
                 lazy_alloc: bool = False, prefill_chunk: int = 0,
                 prefix_share: bool = False, num_swap_blocks: int = 0,
+                keep_logits: int = 0,
                 options: Optional[CompileOptions] = None) -> dict:
     """Serve ``requests`` with continuous batching over the paged cache.
 
@@ -263,6 +265,10 @@ def serve_paged(model, params, requests: Sequence[Request], *,
     interleaved with decode steps.  ``prefix_share`` content-hashes
     prompt blocks and maps shared prefixes into multiple page tables
     (refcounted, copy-on-write on the first divergent append).
+    ``keep_logits`` copies to the host, into ``Request.logits``, the
+    logits row each request's first ``keep_logits`` tokens were sampled
+    from (its prefill, then its first decode steps) — what a check
+    against a reference model compares.
 
     Returns a dict with the finished Request objects (tokens + per-token
     emission timestamps relative to the serving clock), decode step
@@ -322,6 +328,11 @@ def serve_paged(model, params, requests: Sequence[Request], *,
             return fn(params, batch)
 
         key = jax.random.PRNGKey(seed)
+
+        def keep(req: Request, row):
+            if len(req.tokens) < keep_logits:
+                req.logits.append(
+                    np.asarray(row, np.float32)[:cfg.vocab_size])
 
         def sample(logits):
             nonlocal key
@@ -469,6 +480,7 @@ def serve_paged(model, params, requests: Sequence[Request], *,
             if req.prefill_pos < req.prompt_len:
                 return
             del prefilling[slot]     # prompt fully cached: start decode
+            keep(req, logits)
             tok = int(np.asarray(sample(logits)))
             req.tokens.append(tok)
             req.token_times.append(clock())
@@ -496,6 +508,7 @@ def serve_paged(model, params, requests: Sequence[Request], *,
                 logits, cache = run_prefill(req)
                 pools = scatter(pools, cache["kv"],
                                 jnp.asarray(req.blocks, jnp.int32))
+                keep(req, logits[0])
                 tok = int(np.asarray(sample(logits[0])))
                 req.tokens.append(tok)
                 req.token_times.append(clock())
@@ -529,10 +542,15 @@ def serve_paged(model, params, requests: Sequence[Request], *,
             scan_arrivals()          # overlapped host-side scheduling
             tok_host = np.asarray(jax.block_until_ready(tok_dev))
             t_emit = clock()
+            rows = None
             for slot in range(n_slots):
                 req = sched.active[slot]
                 if req is None or slot in prefilling:
                     continue         # inactive slots appended to scrap
+                if len(req.tokens) < keep_logits:
+                    if rows is None:
+                        rows = np.asarray(logits, np.float32)
+                    keep(req, rows[slot])
                 req.tokens.append(int(tok_host[slot]))
                 req.token_times.append(t_emit)
                 if req.done:
@@ -613,7 +631,7 @@ def main(argv=None) -> int:
     p.add_argument("--sample", action="store_true",
                    help="sample instead of greedy argmax decode")
     p.add_argument("--seed", type=int, default=0,
-                   help="root PRNG seed for prompts and sampling")
+                   help="root PRNG seed for weights, prompts and sampling")
     p.add_argument("--paged", action="store_true",
                    help="serve with the continuous-batching engine over "
                         "the block-paged KV cache (see epilog)")
@@ -647,9 +665,11 @@ def main(argv=None) -> int:
                    help="Poisson arrival rate (requests/s); default: all "
                         "requests arrive at t=0")
     args = p.parse_args(argv)
+    print(env.device_line())
+    env.enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     model = build_model(cfg)
-    params = steps_mod.cast_compute(model.init(0), cfg.compute_dtype)
+    params = steps_mod.cast_compute(model.init(args.seed), cfg.compute_dtype)
     if args.paged:
         reqs = make_requests(args.requests, prompt_len=args.prompt_len,
                              gen_len=args.gen_len, vocab=cfg.vocab_size,

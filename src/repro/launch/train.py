@@ -26,6 +26,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMDataset
 from repro.dist import sharding as shd
+from repro.launch import env
 from repro.launch import steps as steps_mod
 from repro.models.model import build_model
 from repro.optim import OptimizerConfig
@@ -139,6 +140,8 @@ def main(argv=None) -> int:
     p.add_argument("--microbatches", type=int, default=1)
     p.add_argument("--remat", default="none")
     args = p.parse_args(argv)
+    print(env.device_line())
+    env.enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     hp = steps_mod.TrainHParams(
         optimizer=OptimizerConfig(total_steps=args.steps,

@@ -238,6 +238,9 @@ class Request:
     prefill_pos: int = 0           # chunked-prefill progress (tokens done)
     admitted_at: Optional[float] = None
     finished_at: Optional[float] = None
+    # logits row (host copy) behind each of the first tokens, kept only
+    # when the engine is asked to (serve_paged's ``keep_logits``)
+    logits: List["object"] = dataclasses.field(default_factory=list)
 
     @property
     def prompt_len(self) -> int:

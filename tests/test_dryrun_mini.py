@@ -21,6 +21,7 @@ _SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.dist import sharding as shd
     from repro.launch import steps as steps_mod, hlo as hlo_mod
+    from repro.launch.mesh import make_mesh
     from repro.launch.shapes import batch_specs, decode_specs
     from repro.models.model import build_model
     from repro.optim import OptimizerConfig
@@ -28,7 +29,7 @@ _SCRIPT = textwrap.dedent("""
     arch, kind = sys.argv[1], sys.argv[2]
     cfg = get_config(arch, reduced=True)
     model = build_model(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     hp = steps_mod.TrainHParams(
         optimizer=OptimizerConfig(), microbatches=2)
     with shd.use_mesh(mesh):

@@ -26,6 +26,7 @@ import contextlib
 import dataclasses
 import io
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -90,6 +91,28 @@ def test_paged_matches_contiguous_every_backend(models, arch, target):
     for r in out["requests"]:
         assert len(r.tokens) == r.gen_len
         assert r.tokens == refs[r.rid], (arch, target, r.rid)
+
+
+def test_keep_logits_rows_are_the_sampled_ones(models):
+    """``keep_logits=2`` keeps, per request, the rows its first two tokens
+    were sampled from: the prefill's last-token logits, then the first
+    decode step's — what chip_smoke.py compares with its references."""
+    model, params = models["qwen2-1.5b"]
+    V = model.cfg.vocab_size
+    reqs = make_requests(4, prompt_len=6, gen_len=3, vocab=V, seed=5,
+                         ragged=True)
+    out = serve_paged(model, params, reqs, n_slots=2, block_size=4,
+                      num_blocks=9, keep_logits=2)
+    for r in out["requests"]:
+        assert len(r.logits) == min(2, r.gen_len)
+        for row, tok in zip(r.logits, r.tokens):
+            assert row.shape == (V,) and row.dtype == np.float32
+            assert int(np.argmax(row)) == tok
+        prefill = jax.jit(lambda p, b, n=r.prompt_len: model.prefill(
+            p, b, max_len=n))
+        logits, _ = prefill(params, {"tokens": jnp.asarray(r.prompt[None])})
+        np.testing.assert_allclose(
+            r.logits[0], np.asarray(logits[0, :V], np.float32), atol=1e-5)
 
 
 @pytest.mark.parametrize("arch", ARCHS)

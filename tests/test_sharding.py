@@ -7,17 +7,10 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.dist import sharding as shd
 
 
-def _amesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)          # jax >= 0.5 signature
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def _mesh(multi=False):
     if multi:
-        return _amesh((2, 16, 16), ("pod", "data", "model"))
-    return _amesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_rules_fsdp_plus_tp():
