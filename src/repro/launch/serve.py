@@ -27,6 +27,12 @@ The engine (:func:`serve_paged`) replaces the seed's fixed-wave loop:
   common prompt prefix map the same physical blocks (refcounted); the
   first divergent append forks the shared block via a compiled
   ``paged.copy``.
+* **host spans on the profiler's clock**: each phase of a loop iteration
+  runs inside a ``jax.profiler.TraceAnnotation`` named by an ``SPAN_*``
+  constant below, and the jitted programs carry stable names
+  (``paged_decode``, ``kv_scatter``, ``prefill``, ``prefill_chunk``), so
+  a trace taken around a serving window (``jax.profiler.trace``) shows
+  what the host did in every device gap.
 
 All block movement — gather, append, swap, fork — lowers through the
 ``paged_to_kokkos`` pass to ``kokkos.page_*`` IR (visible under
@@ -51,6 +57,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_config
 from repro.core import ops as cops
@@ -163,6 +170,23 @@ def make_requests(n: int, *, prompt_len: int, gen_len: int, vocab: int,
     return reqs
 
 
+# Host spans of the engine loop, one per phase of an iteration (never one
+# per slot or per token).  They are written into the profiler's trace
+# while one is being taken and cost under a microsecond each otherwise.
+SPAN_ITERATION = "engine.iteration"   # one pass of the loop; arg: step
+SPAN_ADMIT = "engine.admit"           # arrivals and admission
+SPAN_PREFILL = "engine.prefill"       # prefill, scatter, first token; rid
+SPAN_CHUNK = "engine.chunk"           # one prefill chunk; rid, size
+SPAN_SWAP_OUT = "engine.swap_out"     # preemption copy; rid
+SPAN_SWAP_IN = "engine.swap_in"       # resume copy; rid
+SPAN_FORK = "engine.fork"             # copy-on-write block copy; rid
+SPAN_PREPARE = "engine.prepare"       # page tables and decode inputs
+SPAN_DISPATCH = "engine.dispatch"     # decode step and sample; batch
+SPAN_SCAN = "engine.scan"             # arrivals while the step runs
+SPAN_READBACK = "engine.readback"     # wait for and copy the tokens
+SPAN_EMIT = "engine.emit"             # append tokens, retire requests
+SPAN_IDLE = "engine.idle"             # sleep until the next arrival
+
 ENGINE_CACHE_CAP = 8      # (geometry, quantized, backend) cache entries
 PREFILL_CACHE_CAP = 32    # per-length prefill / chunk programs per entry
 ENGINE_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
@@ -219,15 +243,18 @@ def _engine_fns(model, block_size: int, quantized: bool,
     fns = cache.get(key)
     if fns is None:
         ENGINE_CACHE_STATS["misses"] += 1
+
+        # named functions, so that a trace shows jit_paged_decode(...)
+        def paged_decode(p, t, c, tb, ln):
+            return model.paged_decode_step(p, t, c, tb, ln,
+                                           block_size=block_size)
+
+        def kv_scatter(c, kv, ids):
+            return serve_mod.scatter_prefill_paged(c, kv, ids, block_size)
+
         fns = {
-            "decode": jax.jit(
-                lambda p, t, c, tb, ln: model.paged_decode_step(
-                    p, t, c, tb, ln, block_size=block_size),
-                donate_argnums=(2,)),
-            "scatter": jax.jit(
-                lambda c, kv, ids: serve_mod.scatter_prefill_paged(
-                    c, kv, ids, block_size),
-                donate_argnums=(0,)),
+            "decode": jax.jit(paged_decode, donate_argnums=(2,)),
+            "scatter": jax.jit(kv_scatter, donate_argnums=(0,)),
             "prefill": _LruDict(PREFILL_CACHE_CAP),  # per prompt length
             "chunk": _LruDict(PREFILL_CACHE_CAP),    # per chunk length
         }
@@ -235,6 +262,21 @@ def _engine_fns(model, block_size: int, quantized: bool,
         ENGINE_CACHE_STATS["hits"] += 1
     cache[key] = fns                 # insert or LRU-touch
     return fns
+
+
+def _prefill_program(model, n: int, quantized: bool):
+    """The prefill of an ``n``-token prompt, named ``prefill``."""
+    def prefill(p, b):
+        return model.prefill(p, b, max_len=n, quantized=quantized)
+    return jax.jit(prefill)
+
+
+def _chunk_program(model, block_size: int):
+    """One chunk of a chunked prefill, named ``prefill_chunk``."""
+    def prefill_chunk(p, t, s, c, tr):
+        return model.paged_prefill_chunk(p, t, s, c, tr,
+                                         block_size=block_size)
+    return jax.jit(prefill_chunk, donate_argnums=(3,))
 
 
 def serve_paged(model, params, requests: Sequence[Request], *,
@@ -319,11 +361,9 @@ def serve_paged(model, params, requests: Sequence[Request], *,
         chunk_fns: _LruDict = fns["chunk"]
 
         def run_prefill(req: Request):
-            fn = _cached(
-                prefill_fns, req.prompt_len,
-                lambda: jax.jit(
-                    lambda p, b, _n=req.prompt_len: model.prefill(
-                        p, b, max_len=_n, quantized=quantized)))
+            fn = _cached(prefill_fns, req.prompt_len,
+                         lambda: _prefill_program(model, req.prompt_len,
+                                                  quantized))
             batch = {"tokens": jnp.asarray(req.prompt[None], jnp.int32)}
             return fn(params, batch)
 
@@ -370,34 +410,36 @@ def serve_paged(model, params, requests: Sequence[Request], *,
             the pool blocks — a freed block can be reallocated and
             overwritten by the very next admission."""
             nonlocal swap_pools
-            try:
-                sids = swap_alloc.alloc(len(victim.blocks))
-            except PagePoolExhausted as e:
-                raise PagePoolExhausted(
-                    f"swap arena exhausted while preempting request "
-                    f"{victim.rid}: {e}; {sched.describe_usage()}"
-                ) from None
-            src = np.asarray(victim.blocks, np.int32)
-            dst = np.asarray(sids, np.int32)
-            for k in swap_pools:
-                swap_pools[k] = cops.page_swap_out(
-                    swap_pools[k], pools[k], src, dst,
-                    block_size=block_size)
-            prefilling.pop(victim.slot, None)
-            sched.preempt(victim.slot, sids)
+            with TraceAnnotation(SPAN_SWAP_OUT, rid=victim.rid):
+                try:
+                    sids = swap_alloc.alloc(len(victim.blocks))
+                except PagePoolExhausted as e:
+                    raise PagePoolExhausted(
+                        f"swap arena exhausted while preempting request "
+                        f"{victim.rid}: {e}; {sched.describe_usage()}"
+                    ) from None
+                src = np.asarray(victim.blocks, np.int32)
+                dst = np.asarray(sids, np.int32)
+                for k in swap_pools:
+                    swap_pools[k] = cops.page_swap_out(
+                        swap_pools[k], pools[k], src, dst,
+                        block_size=block_size)
+                prefilling.pop(victim.slot, None)
+                sched.preempt(victim.slot, sids)
 
         def swap_in(req: Request):
             """Re-admission of a preempted request: restore its saved
             blocks into the freshly allocated ``req.blocks``."""
             nonlocal pools
-            src = np.asarray(req.swap_blocks, np.int32)
-            dst = np.asarray(req.blocks, np.int32)
-            for k in pools:
-                pools[k] = cops.page_swap_in(
-                    pools[k], swap_pools[k], src, dst,
-                    block_size=block_size)
-            swap_alloc.release(req.swap_blocks)
-            req.swap_blocks = []
+            with TraceAnnotation(SPAN_SWAP_IN, rid=req.rid):
+                src = np.asarray(req.swap_blocks, np.int32)
+                dst = np.asarray(req.blocks, np.int32)
+                for k in pools:
+                    pools[k] = cops.page_swap_in(
+                        pools[k], swap_pools[k], src, dst,
+                        block_size=block_size)
+                swap_alloc.release(req.swap_blocks)
+                req.swap_blocks = []
 
         def ensure_append_capacity():
             """Before a decode step, make sure every decoding slot owns
@@ -428,10 +470,11 @@ def serve_paged(model, params, requests: Sequence[Request], *,
                         src_bid, dst_bid = fork
                         s = np.asarray([src_bid], np.int32)
                         d = np.asarray([dst_bid], np.int32)
-                        for k in pools:
-                            pools[k] = cops.page_copy(
-                                pools[k], pools[k], s, d,
-                                block_size=block_size)
+                        with TraceAnnotation(SPAN_FORK, rid=req.rid):
+                            for k in pools:
+                                pools[k] = cops.page_copy(
+                                    pools[k], pools[k], s, d,
+                                    block_size=block_size)
                     break
 
         def sync_slots():
@@ -464,38 +507,40 @@ def serve_paged(model, params, requests: Sequence[Request], *,
             req = prefilling[slot]
             start = req.prefill_pos
             size = min(prefill_chunk, req.prompt_len - start)
-            fn = _cached(
-                chunk_fns, size,
-                lambda: jax.jit(
-                    lambda p, t, s, c, tr: model.paged_prefill_chunk(
-                        p, t, s, c, tr, block_size=block_size),
-                    donate_argnums=(3,)))
-            row = np.zeros((max_blocks,), np.int32)
-            row[:len(req.blocks)] = req.blocks
-            logits, pools = fn(
-                params,
-                jnp.asarray(req.prompt[start:start + size], jnp.int32),
-                jnp.asarray(start, jnp.int32), pools, jnp.asarray(row))
-            req.prefill_pos += size
-            if req.prefill_pos < req.prompt_len:
-                return
-            del prefilling[slot]     # prompt fully cached: start decode
-            keep(req, logits)
-            tok = int(np.asarray(sample(logits)))
-            req.tokens.append(tok)
-            req.token_times.append(clock())
-            if req.done:             # gen_len == 1: prefill was enough
-                retire(slot, req, clock())
+            with TraceAnnotation(SPAN_CHUNK, rid=req.rid, size=size):
+                fn = _cached(chunk_fns, size,
+                             lambda: _chunk_program(model, block_size))
+                row = np.zeros((max_blocks,), np.int32)
+                row[:len(req.blocks)] = req.blocks
+                logits, pools = fn(
+                    params,
+                    jnp.asarray(req.prompt[start:start + size], jnp.int32),
+                    jnp.asarray(start, jnp.int32), pools, jnp.asarray(row))
+                req.prefill_pos += size
+                if req.prefill_pos < req.prompt_len:
+                    return
+                del prefilling[slot]  # prompt fully cached: start decode
+                keep(req, logits)
+                tok = int(np.asarray(sample(logits)))
+                req.tokens.append(tok)
+                req.token_times.append(clock())
+                if req.done:          # gen_len == 1: prefill was enough
+                    retire(slot, req, clock())
 
-        while sched.has_work() or idx < len(requests):
-            scan_arrivals()
-            if policy == "static" and (
-                    sched.n_active > 0
-                    or (len(sched.pending) < n_slots
-                        and idx < len(requests))):
-                admitted = []        # wave barrier: wait to fill / drain
-            else:
-                admitted = sched.admit(clock())
+        def iteration():
+            """One pass of the engine loop: admissions and their
+            prefills, one prefill chunk, then one decode step over every
+            decodable slot; each phase in its own span."""
+            nonlocal pools, steps
+            with TraceAnnotation(SPAN_ADMIT):
+                scan_arrivals()
+                if policy == "static" and (
+                        sched.n_active > 0
+                        or (len(sched.pending) < n_slots
+                            and idx < len(requests))):
+                    admitted = []    # wave barrier: wait to fill / drain
+                else:
+                    admitted = sched.admit(clock())
             for slot, req in admitted:
                 if req.swap_blocks:  # resumed from the swap tier
                     swap_in(req)
@@ -505,16 +550,18 @@ def serve_paged(model, params, requests: Sequence[Request], *,
                 if prefill_chunk and req.prompt_len > prefill_chunk:
                     prefilling[slot] = req       # chunked: interleaved
                     continue
-                logits, cache = run_prefill(req)
-                pools = scatter(pools, cache["kv"],
-                                jnp.asarray(req.blocks, jnp.int32))
-                keep(req, logits[0])
-                tok = int(np.asarray(sample(logits[0])))
-                req.tokens.append(tok)
-                req.token_times.append(clock())
-                req.prefill_pos = req.prompt_len
-                if req.done:         # gen_len == 1: prefill was enough
-                    retire(slot, req, clock())
+                with TraceAnnotation(SPAN_PREFILL, rid=req.rid,
+                                     prompt_len=req.prompt_len):
+                    logits, cache = run_prefill(req)
+                    pools = scatter(pools, cache["kv"],
+                                    jnp.asarray(req.blocks, jnp.int32))
+                    keep(req, logits[0])
+                    tok = int(np.asarray(sample(logits[0])))
+                    req.tokens.append(tok)
+                    req.token_times.append(clock())
+                    req.prefill_pos = req.prompt_len
+                    if req.done:     # gen_len == 1: prefill was enough
+                        retire(slot, req, clock())
             if prefilling:
                 # chunked prefill: one chunk per engine iteration,
                 # interleaved with the decode step below so one long
@@ -528,33 +575,46 @@ def serve_paged(model, params, requests: Sequence[Request], *,
                         and idx < len(requests):
                     # idle until the next arrival (open-loop load; the
                     # static policy also waits here for its wave)
-                    time.sleep(max(requests[idx].arrival - clock(), 0.0))
-                continue
-            ensure_append_capacity()
-            sync_slots()
+                    with TraceAnnotation(SPAN_IDLE):
+                        time.sleep(max(requests[idx].arrival - clock(),
+                                       0.0))
+                return
+            with TraceAnnotation(SPAN_PREPARE):
+                ensure_append_capacity()
+                sync_slots()
+                tok_in = jnp.asarray(next_tok)
+                table_in = jnp.asarray(table)
+                lengths_in = jnp.asarray(lengths)
             # async dispatch: the decode step is in flight on the device
             # while the host scans arrivals and plans admissions below
-            logits, pools = decode(params, jnp.asarray(next_tok), pools,
-                                   jnp.asarray(table),
-                                   jnp.asarray(lengths))
-            tok_dev = sample(logits)
+            with TraceAnnotation(SPAN_DISPATCH, batch=decodable):
+                logits, pools = decode(params, tok_in, pools, table_in,
+                                       lengths_in)
+                tok_dev = sample(logits)
             steps += 1
-            scan_arrivals()          # overlapped host-side scheduling
-            tok_host = np.asarray(jax.block_until_ready(tok_dev))
-            t_emit = clock()
-            rows = None
-            for slot in range(n_slots):
-                req = sched.active[slot]
-                if req is None or slot in prefilling:
-                    continue         # inactive slots appended to scrap
-                if len(req.tokens) < keep_logits:
-                    if rows is None:
-                        rows = np.asarray(logits, np.float32)
-                    keep(req, rows[slot])
-                req.tokens.append(int(tok_host[slot]))
-                req.token_times.append(t_emit)
-                if req.done:
-                    retire(slot, req, t_emit)
+            with TraceAnnotation(SPAN_SCAN):
+                scan_arrivals()      # overlapped host-side scheduling
+            with TraceAnnotation(SPAN_READBACK):
+                tok_host = np.asarray(jax.block_until_ready(tok_dev))
+            with TraceAnnotation(SPAN_EMIT):
+                t_emit = clock()
+                rows = None
+                for slot in range(n_slots):
+                    req = sched.active[slot]
+                    if req is None or slot in prefilling:
+                        continue     # inactive slots appended to scrap
+                    if len(req.tokens) < keep_logits:
+                        if rows is None:
+                            rows = np.asarray(logits, np.float32)
+                        keep(req, rows[slot])
+                    req.tokens.append(int(tok_host[slot]))
+                    req.token_times.append(t_emit)
+                    if req.done:
+                        retire(slot, req, t_emit)
+
+        while sched.has_work() or idx < len(requests):
+            with TraceAnnotation(SPAN_ITERATION, step=steps):
+                iteration()
 
     total_tokens = sum(len(r.tokens) for r in requests)
     telemetry = sched.telemetry()
