@@ -84,7 +84,9 @@ def _op_callable(op: Op, options: CompileOptions) -> Optional[Callable]:
     if op.opname == "sparse.convert":
         from repro.kernels.spmv import as_ell
         mx = op.attrs.get("max_nnz_row")
-        return lambda a, _mx=mx: as_ell(a, max_nnz_row=_mx)
+        interpret = options.resolve_interpret()
+        return lambda a, _mx=mx: as_ell(a, max_nnz_row=_mx,
+                                        interpret=interpret)
     if op.opname == "kokkos.fused":
         # an unlowered fused region (e.g. mixed operand shapes kept it at
         # tensor level): interpret the structured body; XLA fuses the jnp
@@ -379,12 +381,19 @@ def _sparse_convert(a, max_nnz_row):
         return ("ell", jnp.zeros((n_rows, width), val.dtype),
                 jnp.zeros((n_rows, width), jnp.int32),
                 jnp.zeros((n_rows, width), bool), n_rows, n_cols)
-    offs = jnp.arange(width)[None, :]
     row_len = ip[1:] - ip[:-1]
-    idx = jnp.clip(ip[:-1, None] + offs, 0, val.shape[0] - 1)
-    valid = offs < row_len[:, None]
-    vals = jnp.where(valid, val[idx], 0).astype(val.dtype)
-    cols = jnp.where(valid, ind[idx], 0).astype(jnp.int32)
+    valid = jnp.arange(width)[None, :] < row_len[:, None]
+
+    def windows(arr):
+        # row r is the contiguous window arr[ip[r] : ip[r] + width]; the
+        # tail pad keeps dynamic_slice from shifting the last rows'
+        # windows back to fit
+        padded = jnp.pad(arr, (0, width))
+        return jax.vmap(lambda s: jax.lax.dynamic_slice(
+            padded, (s,), (width,)))(ip[:-1])
+
+    vals = jnp.where(valid, windows(val), 0).astype(val.dtype)
+    cols = jnp.where(valid, windows(ind), 0).astype(jnp.int32)
     return ("ell", vals, cols, valid, n_rows, n_cols)
 
 

@@ -139,7 +139,7 @@ def spmv_pallas(a, x, *, tiling=None, max_nnz_row=None, interpret=False):
         # the layout conversion is not jit-safe — run library semantics
         return _sp.spmv_reference(a, x)
     t = tiling or {}
-    ell = _sp.as_ell(a, max_nnz_row=max_nnz_row)
+    ell = _sp.as_ell(a, max_nnz_row=max_nnz_row, interpret=interpret)
     return _sp.spmv_ell(ell, x, row_block=t.get("row_block", 256),
                         row_width=t.get("row_width", 128),
                         interpret=interpret)

@@ -74,6 +74,6 @@ def spmm_ell(ell: EllMatrix, b: jax.Array, *, row_block: int = 128,
 def spmm_sparse(a, b, *, row_block: int = 128, row_width: int = 128,
                 max_nnz_row: int = None, interpret: bool = False):
     """Packed-operand entry point (CsrMatrix or EllMatrix)."""
-    ell = as_ell(a, max_nnz_row=max_nnz_row)
+    ell = as_ell(a, max_nnz_row=max_nnz_row, interpret=interpret)
     return spmm_ell(ell, b, row_block=row_block, row_width=row_width,
                     interpret=interpret)

@@ -121,6 +121,38 @@ def test_emitted_source_nnz_zero_with_ell_convert(rng, tmp_path):
     np.testing.assert_allclose(np.asarray(y), np.zeros(n), atol=0)
 
 
+def test_emitted_source_ell_windows_match_loops(rng):
+    """The freestanding source's windowed _sparse_convert gives the same
+    SpMV as the compiled loops backend on a matrix with empty rows whose
+    last row is the longest (its window runs into the tail pad)."""
+    lens = np.array([3, 0, 5, 0, 0, 2, 1, 0, 13])
+    n, m = len(lens), 11
+    ip = np.concatenate([[0], np.cumsum(lens)]).astype(np.int32)
+    ind = rng.integers(0, m, ip[-1]).astype(np.int32)
+    val = rng.standard_normal(ip[-1]).astype(np.float32)
+    x = rng.standard_normal(m).astype(np.float32)
+    specs = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+             for a in (ip, ind, val, x)]
+
+    def f(ipv, indv, valv, xv):
+        return ops.spmv_csr(ipv, indv, valv, xv, n_rows=n,
+                            max_nnz_row=int(lens.max()))
+
+    mod = pipeline.compile(f, *specs, options=CompileOptions(
+        target="loops", fuse_elementwise=False))
+    assert "sparse.convert" in [o.opname for o in mod.graph.ops]
+    g: dict = {}
+    exec(compile(mod.emit_source(), "<gen>", "exec"), g)
+    y = g[mod.graph.name](ip, ind, val, x)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(mod(ip, ind, val, x)),
+                               rtol=1e-6, atol=1e-6)
+    dense = np.zeros((n, m), np.float32)
+    for r in range(n):
+        np.add.at(dense[r], ind[ip[r]:ip[r + 1]], val[ip[r]:ip[r + 1]])
+    np.testing.assert_allclose(np.asarray(y), dense @ x, rtol=1e-5,
+                               atol=1e-5)
+
+
 def test_no_registry_bypass_in_tracing():
     """spmv_csr inside a trace emits the composite sparse form — a
     sparse-encoded pack feeding linalg.spmv_csr, no loose-operand op."""

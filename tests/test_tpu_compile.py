@@ -103,6 +103,20 @@ def test_spmv_ell_stocf_rows(one_chip):
     assert got == {"spmv_ell": 1}
 
 
+def test_csr_to_ell_stocf_rows(one_chip):
+    """The CSR to ELL conversion at StocF-1465's size is the one
+    ``csr_to_ell`` window kernel, with no scalar gather beside it."""
+    from repro.kernels.spmv import csr_to_ell
+    s = lambda *a: _spec(one_chip, *a)  # noqa: E731
+    n, nnz = STOCF_ROWS, 21_009_064                 # nnz mean 14.34
+    hlo = jax.jit(lambda ip, ind, val: csr_to_ell(
+        ip, ind, val, n, n, max_nnz_row=189, interpret=False)).lower(
+        s((n + 1,), "int32"), s((nnz,), "int32"),
+        s((nnz,), "float32")).compile().as_text()
+    assert kernel_calls(hlo) == {"csr_to_ell": 1}
+    assert "gather" not in hlo
+
+
 def test_paged_decode_step(one_chip):
     """The whole 28-layer paged decode step on the pallas target: every
     attention-path kernel is a custom call and the step fits one chip."""
