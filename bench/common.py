@@ -8,10 +8,12 @@ checkout's ``src/`` on the path when a runner needs it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
 import pathlib
+import re
 import sys
 import time
 from typing import Callable, Dict, List, Optional
@@ -59,6 +61,7 @@ class Cell:
     traffic: dict         # bench/traffic/<traffic>.json
     end_to_end: List[dict]
     per_layer: List[dict]
+    root: pathlib.Path = ROOT   # the checkout whose files the cell names
 
 
 def _metric_applies(metric: dict, workload: str, e2e_names) -> bool:
@@ -87,17 +90,43 @@ def cell(name: str, root: pathlib.Path = ROOT) -> Cell:
                 traffic_name=wl["traffic"],
                 traffic=load_json(root / "bench" / "traffic" /
                                   f"{wl['traffic']}.json"),
-                end_to_end=e2e, per_layer=per_layer)
+                end_to_end=e2e, per_layer=per_layer, root=root)
+
+
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _file_module(kind: str, name: str, root: pathlib.Path):
     """``bench/<kind>/<name>.py``, loaded by its path."""
-    path = root / "bench" / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _load(root / "bench" / kind / f"{name}.py",
+                 f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}")
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_module(path: pathlib.Path):
+    # once per file and process: its jitted functions keep their compiles
+    return _load(path, "bench_reference_" + re.sub(r"\W", "_", str(path)))
+
+
+def reference(cfg: dict, root: pathlib.Path = ROOT):
+    """The plain reference module that a served configuration's file
+    names under ``"reference"``, a path from the checkout's root.  It
+    gives what depends on the architecture (``bench/serve.py``).  Refuses
+    a file that names none, and a path outside the benchmark's
+    ``paths``."""
+    if "reference" not in cfg:
+        raise KeyError("the configuration's file names no \"reference\"")
+    paths = benchmark(root)["paths"]
+    path = (root / cfg["reference"]).resolve()
+    if path.suffix != ".py" or not any(
+            path.is_relative_to((root / p).resolve()) for p in paths):
+        raise ValueError(f"reference {cfg['reference']!r} is not a Python "
+                         f"file under the benchmark's paths {paths}")
+    return _reference_module(path)
 
 
 def metric_reader(name: str, root: pathlib.Path = ROOT) -> Callable:
@@ -112,10 +141,10 @@ def program_maker(name: str, root: pathlib.Path = ROOT) -> Callable:
     return _file_module("programs", name, root).make
 
 
-def peaks(device_kind: str) -> dict:
+def peaks(device_kind: str, root: pathlib.Path = ROOT) -> dict:
     """The chip's published peaks (``bench/peaks.json``).  A device
     that is not in the table is an error, never a default."""
-    table = load_json(ROOT / "bench" / "peaks.json")
+    table = load_json(root / "bench" / "peaks.json")
     if device_kind not in table["devices"]:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"bench/peaks.json")

@@ -6,7 +6,8 @@ The mix's file names the input program, found by that name in
 from the seed, ``pipeline.compile`` with the configuration's
 target, one warm call, then calls back to back for the window, each
 ending in ``block_until_ready``; the answers of a sample of the calls,
-drawn from the seed, are compared with a plain reference afterwards.
+drawn from the seed, are compared with a plain reference afterwards
+(all of each answer, or the rows the program names).
 """
 from __future__ import annotations
 
@@ -31,6 +32,8 @@ class Program:
     control: Callable               # args -> answer at the control's
     flops: float                    #   precision
     bytes: float
+    rows: Optional[np.ndarray] = None   # the answer rows that reference
+    #                                     and control give; all if None
 
 
 def rel_err(got, ref) -> float:
@@ -39,6 +42,11 @@ def rel_err(got, ref) -> float:
     ref = np.asarray(ref, np.float64)
     return float(np.max(np.abs(got - ref)) / max(np.max(np.abs(ref)),
                                                  1e-30))
+
+
+def checked(program: Program, answer) -> np.ndarray:
+    """The part of an answer that the reference gives."""
+    return answer if program.rows is None else answer[program.rows]
 
 
 # ---------------------------------------------------------------------------
@@ -58,7 +66,7 @@ def set_up(cell: common.Cell, seed: int) -> Setup:
     from repro.core import pipeline
     from repro.core.options import CompileOptions
     cfg, mix = cell.config, cell.traffic
-    prog = common.program_maker(mix["program"])(cfg, mix, seed)
+    prog = common.program_maker(mix["program"], cell.root)(cfg, mix, seed)
     opts = CompileOptions(target=cfg["target"],
                           interpret=cfg.get("interpret"))
     mod = pipeline.compile(prog.fn, *prog.args, options=opts,
@@ -78,26 +86,30 @@ class Called:
 
 def run_window(s: Setup, seed: int, seconds: float,
                trace_dir: Optional[str] = None,
-               trace_calls: int = 1) -> Called:
+               trace: Optional[dict] = None) -> Called:
     """Calls back to back until ``seconds`` have passed; the call in
     flight at the end completes and counts.  A reservoir of the answers,
     drawn from the seed, is kept for the check.  With ``trace_dir`` the
-    first ``trace_calls`` calls run under the profiler, inside a
-    ``bench.traced`` span."""
+    mix's ``trace["calls"]`` calls that follow the first
+    ``trace["start_s"]`` seconds run under the profiler, inside a
+    ``bench.traced`` span; the window lasts until they have ended."""
     import jax
+    trace = trace or {}
     rng = np.random.default_rng([seed, 3])
     kept: list = []
     n = 0
     traced = None
+    to_trace = trace.get("calls", 1) if trace_dir else 0
     t0 = time.monotonic()
-    if trace_dir:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        traced = jax.profiler.TraceAnnotation("bench.traced")
-        traced.__enter__()
     try:
         while True:
+            if (to_trace and traced is None
+                    and time.monotonic() - t0 >= trace.get("start_s", 0.0)):
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
+                traced = jax.profiler.TraceAnnotation("bench.traced")
+                traced.__enter__()
             with jax.profiler.TraceAnnotation("bench.call"):
                 y = jax.block_until_ready(s.module(*s.dev_args))
             if len(kept) < KEEP:
@@ -107,11 +119,13 @@ def run_window(s: Setup, seed: int, seconds: float,
                 if j < KEEP:
                     kept[j] = y
             n += 1
-            if traced is not None and n == trace_calls:
-                traced.__exit__(None, None, None)
-                jax.profiler.stop_trace()
-                traced = None
-            if time.monotonic() - t0 >= seconds and traced is None:
+            if traced is not None:
+                to_trace -= 1
+                if not to_trace:
+                    traced.__exit__(None, None, None)
+                    jax.profiler.stop_trace()
+                    traced = None
+            if time.monotonic() - t0 >= seconds and not to_trace:
                 break
     finally:
         if traced is not None:
@@ -126,7 +140,7 @@ def checks(cell: common.Cell, s: Setup, called: Called):
     """(failed calls, the numbers compared with their limits)."""
     want = s.program.reference(*s.program.args)
     limit = cell.traffic["check"]["rel_err"]
-    errs = [rel_err(y, want) for y in called.kept]
+    errs = [rel_err(checked(s.program, y), want) for y in called.kept]
     failed = sum(1 for e in errs if not e <= limit)
     worst = max(errs) if errs else float("inf")
     return failed, [

@@ -16,6 +16,7 @@ seed and writes them all to ``--out``.
 from __future__ import annotations
 
 import argparse
+import gc
 import json
 import sys
 import time
@@ -25,25 +26,26 @@ from bench import common
 
 def serve_readings(cell, seeds, control_seeds, seconds):
     from bench import serve
+    ref = common.reference(cell.config, cell.root)
     engine = serve.set_up(cell, seeds[0], seconds)
     vocab = cell.config["vocab_size"]
     for seed in seeds:
         t = time.monotonic()
         if seed != seeds[0]:
             engine.weights = serve.make_weights(
-                cell.config, engine.model.cfg.padded_vocab, seed)
+                ref, cell.config, engine.model.cfg.padded_vocab, seed)
         served = serve.run_window(engine, cell.traffic, seed, seconds,
                                   vocab, None)
         failed = serve.failures(served.requests, vocab)
         picked = serve.sample(served.requests, seed,
                               cell.traffic["check"]["sample"])
-        gap, n = serve.widest_gap(engine.weights, cell.config, picked)
+        gap, n = serve.widest_gap(ref, engine.weights, cell.config, picked)
         row = dict({"seed": seed, "program": gap, "compared_tokens": n,
                     "failed": failed, "requests": len(served.requests)},
                    **serve.end_to_end(served))
         if seed in control_seeds:
-            row["control"] = serve.control_gap(engine.weights, cell.config,
-                                               picked)
+            row["control"] = serve.control_gap(ref, engine.weights,
+                                               cell.config, picked)
         row["seconds"] = time.monotonic() - t
         yield row
 
@@ -56,13 +58,14 @@ def compiler_readings(cell, seeds, control_seeds, seconds):
         called = compiler.run_window(s, seed, seconds)
         want = s.program.reference(*s.program.args)
         row = {"seed": seed, "calls": called.calls,
-               "program": max(compiler.rel_err(y, want)
-                              for y in called.kept)}
+               "program": max(compiler.rel_err(compiler.checked(s.program, y),
+                                               want) for y in called.kept)}
         if seed in control_seeds:
             row["control"] = compiler.rel_err(
                 s.program.control(*s.program.args), want)
         row["seconds"] = time.monotonic() - t
         del s
+        gc.collect()            # free this seed's inputs on the device
         yield row
 
 

@@ -20,6 +20,9 @@ Weights are a dict (``embed.table``, ``final_norm.scale`` and stacked
 rounded to float8 e4m3 (a scale per row of the activations and per
 output column of the weights), the precision below the bfloat16 that
 the configuration serves in.
+
+:func:`check_program` holds the program's config to the configuration's
+file: the benchmark serves only a program that is this architecture.
 """
 from __future__ import annotations
 
@@ -32,6 +35,26 @@ HIGHEST = jax.lax.Precision.HIGHEST
 F8_MAX = 448.0
 ROWS = 512          # logits are formed this many positions at a time
 PAD = 1024          # sequences are padded to a multiple of this: few shapes
+
+# what the program's config must say for the configuration's file to be
+# what it serves: program field -> file key
+PROGRAM_FIELDS = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
+                  "n_heads": "num_attention_heads",
+                  "n_kv_heads": "num_key_value_heads",
+                  "d_ff": "intermediate_size", "vocab_size": "vocab_size",
+                  "head_dim": "head_dim", "rope_theta": "rope_theta",
+                  "tie_embeddings": "tie_word_embeddings"}
+
+
+def check_program(cfg: dict, program_cfg) -> None:
+    """Refuses a program config whose sizes differ from the file's, or
+    that lacks the q/k/v bias, RMSNorm or SiLU of a Qwen2 layer."""
+    pc = program_cfg
+    bad = {f: (getattr(pc, f), cfg[k]) for f, k in PROGRAM_FIELDS.items()
+           if k in cfg and getattr(pc, f) != cfg[k]}
+    if bad or not pc.qkv_bias or pc.norm != "rmsnorm" or pc.act != "silu":
+        raise ValueError(f"program config {cfg['program_config']!r} is "
+                         f"not the configuration's file: {bad}")
 
 
 def dims(cfg: dict) -> dict:

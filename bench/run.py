@@ -51,7 +51,7 @@ class Record:
 
 
 def _trace_dir(cell: common.Cell, seed: int) -> str:
-    d = common.ROOT / ".bench_traces" / f"{cell.name}-{seed}"
+    d = cell.root / ".bench_traces" / f"{cell.name}-{seed}"
     shutil.rmtree(d, ignore_errors=True)
     return str(d)
 
@@ -75,8 +75,8 @@ def run_compiler(cell, seed, seconds, trace: bool):
     s = compiler.set_up(cell, seed)
     window_start = time.monotonic()
     tdir = _trace_dir(cell, seed) if trace else None
-    called = compiler.run_window(
-        s, seed, seconds, tdir, cell.traffic.get("trace", {}).get("calls", 1))
+    called = compiler.run_window(s, seed, seconds, tdir,
+                                 cell.traffic.get("trace"))
     peak = common.memory_peak_bytes()
     failed, checks = compiler.checks(cell, s, called)
     e2e = {"call_ms": 1e3 * called.seconds / called.calls}
@@ -112,11 +112,11 @@ def measure(cell: common.Cell, seed: int, seconds: float, trace: bool,
         dev["busy_s"] = reduced.busy_s
         dev["window_s"] = reduced.window_s
         breakdown = reduced.breakdown()
-        rec = Record(cell=cell, peak=common.peaks(device["kind"]),
+        rec = Record(cell=cell, peak=common.peaks(device["kind"], cell.root),
                      setup=setup,
                      window=window, trace=reduced)
         for m in cell.per_layer:
-            v = common.metric_reader(m["name"])(rec)
+            v = common.metric_reader(m["name"], cell.root)(rec)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     return {"correct": correct, "attempted": attempted, "failed": failed,

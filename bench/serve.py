@@ -4,7 +4,15 @@
 One run: weights made on the device from the seed, the cell's shapes
 warmed up, one measured window of the mix's traffic through
 ``repro.launch.serve.serve_paged`` with the default ``CompileOptions()``,
-then the check of the served tokens against ``bench/reference/qwen2.py``.
+then the check of the served tokens against the plain reference that the
+configuration's file names under ``"reference"`` (``common.reference``).
+
+The reference module gives all that depends on the architecture: the
+weight tree it reads (``shapes``), the padding of its sequences
+(``PAD``), its static configuration (``cfg_items``), the gaps of served
+tokens and of the control (``served_gaps``, ``control_gaps``), and the
+check that the program's config is the configuration's file
+(``check_program``).
 """
 from __future__ import annotations
 
@@ -18,21 +26,11 @@ from typing import Optional
 import numpy as np
 
 from bench import common, stats, traffic
-from bench.reference import qwen2 as ref
-
-# what the program's config must say for the configuration's file to be
-# what it serves: program field -> file key
-PROGRAM_FIELDS = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-                  "n_heads": "num_attention_heads",
-                  "n_kv_heads": "num_key_value_heads",
-                  "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-                  "head_dim": "head_dim", "rope_theta": "rope_theta",
-                  "tie_embeddings": "tie_word_embeddings"}
 
 
-def program_model(cfg: dict):
-    """The program's model for the configuration's file; refuses a
-    program config whose sizes differ from the file's."""
+def program_model(ref, cfg: dict):
+    """The program's model for the configuration's file; the reference's
+    ``check_program`` refuses a program config that is not the file's."""
     from repro.configs import get_config
     from repro.models.model import build_model
     # depth is the one cut a configuration may make of the program's
@@ -41,19 +39,15 @@ def program_model(cfg: dict):
                    reduced=cfg.get("program_reduced", False)),
         n_layers=cfg["num_hidden_layers"],
         compute_dtype=cfg["served_dtype"])
-    bad = {f: (getattr(pc, f), cfg[k]) for f, k in PROGRAM_FIELDS.items()
-           if k in cfg and getattr(pc, f) != cfg[k]}
-    if bad or not pc.qkv_bias or pc.norm != "rmsnorm" or pc.act != "silu":
-        raise ValueError(f"program config {cfg['program_config']!r} is "
-                         f"not the configuration's file: {bad}")
+    ref.check_program(cfg, pc)
     return build_model(pc)
 
 
-def make_weights(cfg: dict, padded_vocab: int, seed: int):
+def make_weights(ref, cfg: dict, padded_vocab: int, seed: int):
     """The served weights, made on the device from the seed in one
-    jitted call, in the served dtype.  Matrices N(0, 1/fan_in), the
-    embedding N(0, 0.02) with the padded rows zero, biases N(0, 0.1),
-    norm scales N(1, 0.1)."""
+    jitted call, in the served dtype, with the tree the reference reads.
+    Matrices N(0, 1/fan_in), the embedding N(0, 0.02) with the padded
+    rows zero, biases N(0, 0.1), norm scales N(1, 0.1)."""
     import jax
     import jax.numpy as jnp
     dt = jnp.dtype(cfg["served_dtype"])
@@ -190,7 +184,6 @@ def run_window(engine: Engine, mix: dict, seed: int, seconds: float,
                vocab: int, trace_dir: Optional[str]) -> Served:
     """The measured window: one engine call whose open-loop arrivals
     fill ``[0, seconds)``; every request is waited for."""
-    import jax
     tr = mix.get("trace", {})
     with common.CompileCounter() as cc:
         t0 = time.monotonic()
@@ -199,14 +192,12 @@ def run_window(engine: Engine, mix: dict, seed: int, seconds: float,
             timer = _TraceTimer(t0, tr.get("start_s", 0.0),
                                 tr.get("seconds", seconds), trace_dir)
             timer.start()
-        with jax.profiler.TraceAnnotation("bench.window"):
-            reqs = _requests(traffic.open_loop(mix, seed, seconds, vocab), 0)
-            with jax.profiler.TraceAnnotation("bench.serve_paged"):
-                out = engine.serve(reqs, seed)
-            done = time.monotonic()
-            # onto the window's clock: the engine's starts when its loop
-            # does, and it reports how long that ran
-            _shift(reqs, done - out["seconds"] - t0)
+        reqs = _requests(traffic.open_loop(mix, seed, seconds, vocab), 0)
+        out = engine.serve(reqs, seed)
+        done = time.monotonic()
+        # onto the window's clock: the engine's starts when its loop
+        # does, and it reports how long that ran
+        _shift(reqs, done - out["seconds"] - t0)
         end = time.monotonic() - t0
         if timer is not None:
             timer.join()
@@ -249,16 +240,17 @@ def sample(reqs, seed: int, n: int) -> list:
     return [longest] + [rest[i] for i in sorted(pick)]
 
 
-def _padded(seq: np.ndarray) -> np.ndarray:
-    n = ref.PAD * max(1, math.ceil(len(seq) / ref.PAD))
+def _padded(seq: np.ndarray, pad: int) -> np.ndarray:
+    n = pad * max(1, math.ceil(len(seq) / pad))
     out = np.zeros(n, np.int32)
     out[:len(seq)] = seq
     return out
 
 
-def sequences(reqs) -> list:
+def sequences(reqs, pad: int) -> list:
     """Per request: (the prompt and the served tokens but the last,
-    padded; the served token at each position; the compared slice)."""
+    padded to a multiple of ``pad``; the served token at each position;
+    the compared slice)."""
     out = []
     for r in reqs:
         toks = np.asarray(r.tokens, np.int32)
@@ -266,28 +258,28 @@ def sequences(reqs) -> list:
         served = np.zeros(len(seq), np.int32)
         lo = r.prompt_len - 1
         served[lo:lo + len(toks)] = toks
-        out.append((_padded(seq), _padded(served),
+        out.append((_padded(seq, pad), _padded(served, pad),
                     slice(lo, lo + len(toks))))
     return out
 
 
-def widest_gap(weights, cfg: dict, reqs) -> tuple:
+def widest_gap(ref, weights, cfg: dict, reqs) -> tuple:
     """(widest gap by which a served token's reference logit lies below
     the reference's best, tokens compared)."""
     items = ref.cfg_items(cfg)
     widest, n = 0.0, 0
-    for seq, served, sl in sequences(reqs):
+    for seq, served, sl in sequences(reqs, ref.PAD):
         g = np.asarray(ref.served_gaps(weights, seq, served, items))[sl]
         widest, n = max(widest, float(g.max())), n + len(g)
     return widest, n
 
 
-def control_gap(weights, cfg: dict, reqs, quant: str = "fp8") -> float:
+def control_gap(ref, weights, cfg: dict, reqs, quant: str = "fp8") -> float:
     """The same reading for the control: at each compared position, the
     token that the reference at ``quant`` precision puts first."""
     items = ref.cfg_items(cfg)
     widest = 0.0
-    for seq, _, sl in sequences(reqs):
+    for seq, _, sl in sequences(reqs, ref.PAD):
         g = np.asarray(ref.control_gaps(weights, seq, items, quant))[sl]
         widest = max(widest, float(g.max()))
     return widest
@@ -296,8 +288,9 @@ def control_gap(weights, cfg: dict, reqs, quant: str = "fp8") -> float:
 def set_up(cell: common.Cell, seed: int, seconds: float) -> Engine:
     """The engine with the seed's weights and every shape warmed up."""
     cfg, mix = cell.config, cell.traffic
-    model = program_model(cfg)
-    weights = make_weights(cfg, model.cfg.padded_vocab, seed)
+    ref = common.reference(cfg, cell.root)
+    model = program_model(ref, cfg)
+    weights = make_weights(ref, cfg, model.cfg.padded_vocab, seed)
     check_layout(weights, model)
     widest = max(p + g for p, g in traffic.warmup_sizes(mix, seconds))
     engine = Engine(model, weights, mix, widest)
@@ -311,7 +304,8 @@ def checks(cell: common.Cell, weights, served: Served, seed: int):
     mix, cfg = cell.traffic, cell.config
     failed = failures(served.requests, cfg["vocab_size"])
     picked = sample(served.requests, seed, mix["check"]["sample"])
-    gap, n = widest_gap(weights, cfg, picked)
+    gap, n = widest_gap(common.reference(cfg, cell.root), weights, cfg,
+                        picked)
     limit = mix["check"]["widest_logit_gap"]
     return failed, [
         {"name": "failed_requests", "value": failed, "limit": 0,

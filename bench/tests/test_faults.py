@@ -3,6 +3,7 @@ can hold, on the CPU: the sound program comes out correct; the program
 with a fault planted where it produces its answer, and the control (the
 reference in the program's place at the precision below the
 configuration's), come out not correct."""
+import functools
 import time
 
 import jax.numpy as jnp
@@ -70,8 +71,10 @@ def test_serving_control_reads_above_the_limit(program):
                               cell.config["vocab_size"], None)
     picked = serve.sample(served.requests, 1, cell.traffic["check"]["sample"])
     limit = cell.traffic["check"]["widest_logit_gap"]
-    assert serve.widest_gap(engine.weights, cell.config, picked)[0] <= limit
-    assert serve.control_gap(engine.weights, cell.config, picked) > limit
+    ref = common.reference(cell.config)
+    assert serve.widest_gap(ref, engine.weights, cell.config,
+                            picked)[0] <= limit
+    assert serve.control_gap(ref, engine.weights, cell.config, picked) > limit
 
 
 def test_sound_compiler_run_is_correct(program):
@@ -131,7 +134,8 @@ def test_sound_dense_run_is_correct(program):
 
 def test_dense_control_is_not_correct(program, monkeypatch):
     from bench.reference import dense
-    _replace_program(monkeypatch, dense.swiglu_fp8)
+    _replace_program(monkeypatch, functools.partial(
+        dense.swiglu_fp8, rows=slice(None), eps=1e-6))
     res = _measure(_cell("tiny-qwen2-mlp.json", "tiny-prefill.json",
                          CALL_E2E), 2**31 + 5, 0.2)
     assert not res["correct"]
@@ -142,8 +146,11 @@ def test_dense_answer_altered_where_produced_is_not_correct(program,
     from bench.reference import dense
 
     def altered(*args):
-        y = dense.swiglu_reference(*args).astype(np.float32)
-        y[len(y) // 2, 0] += 0.1 * np.abs(y).max()
+        # every row as the reference gives it, then one element of each
+        # row altered: the check compares a sample of the rows
+        y = dense.swiglu_reference(*args, rows=slice(None),
+                                   eps=1e-6).astype(np.float32)
+        y[:, len(y[0]) // 2] += 0.1 * np.abs(y).max()
         return y
 
     _replace_program(monkeypatch, altered)

@@ -19,8 +19,8 @@ def tiny():
     common.import_program()
     cfg = dict(common.load_json(DATA / "tiny-qwen2.json"),
                served_dtype="float32")
-    model = serve.program_model(cfg)
-    w = serve.make_weights(cfg, model.cfg.padded_vocab, 5)
+    model = serve.program_model(ref, cfg)
+    w = serve.make_weights(ref, cfg, model.cfg.padded_vocab, 5)
     serve.check_layout(w, model)
     return cfg, model, w
 
@@ -51,16 +51,19 @@ def test_served_gaps_are_zero_on_the_reference_own_tokens(tiny):
     assert np.asarray(ref.served_gaps(w, toks, other, items)).min() > 0.0
 
 
-def test_swiglu_reference_and_its_control(monkeypatch):
+def test_swiglu_reference_and_its_control():
     from bench.reference import dense
-    monkeypatch.setattr(dense, "ROWS", 16)
-    x, gate, up, down = dense.swiglu_inputs(64, 32, 96, "float32", 3)
-    g = x @ gate
-    want = np.asarray((jax.nn.silu(g) * (x @ up)) @ down, np.float64)
-    got = dense.swiglu_reference(x, gate, up, down)
-    # row blocks give what one product over every row gives
-    assert got.shape == (64, 32)
-    assert np.max(np.abs(got - want)) / np.max(np.abs(want)) < 1e-5
-    ctrl = dense.swiglu_fp8(x, gate, up, down)
+    args = dense.swiglu_inputs(64, 32, 96, 2, "float32", 3)
+    x = args[0]
+    for norm, gate, up, down in dense.layers_of(args[1:]):
+        h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * norm
+        x = x + (jax.nn.silu(h @ gate) * (h @ up)) @ down
+    want = np.asarray(x, np.float64)
+    rows = np.array([0, 5, 63])
+    got = dense.swiglu_reference(*args, rows=rows, eps=1e-6)
+    # the rows asked for, each as the whole stack over every row gives it
+    assert got.shape == (3, 32)
+    assert np.max(np.abs(got - want[rows])) / np.max(np.abs(want)) < 1e-5
+    ctrl = dense.swiglu_fp8(*args, rows=rows, eps=1e-6)
     err = np.max(np.abs(ctrl - got)) / np.max(np.abs(got))
     assert 1e-3 < err < 0.2          # float8 rounding, not a wrong product

@@ -102,3 +102,39 @@ def test_readers_on_a_chip_trace(served):
         100 * (1 - served.busy_s / served.window_s))
     rec.trace = None
     assert read("decode_step_ms.chat")(rec) is None
+
+
+def test_dense_readers(spmv):
+    """The dense cell's readers: the ``matmul`` kernel's device time per
+    traced call, the call's roofline share by the arithmetic of the
+    sparse cell's, its share of the peak over the traced window, and
+    the idle share; nothing where the trace holds no such kernel or
+    there is no trace."""
+    import types
+
+    from bench import common, work
+
+    read = common.metric_reader
+    flops, bytes_ = work.swiglu_mlp_work(16384, 1536, 8960, 28, "bfloat16")
+    run = types.SimpleNamespace(
+        peak=common.peaks("TPU v5 lite"), trace=spmv,
+        setup=types.SimpleNamespace(program=types.SimpleNamespace(
+            flops=flops, bytes=bytes_)))
+    assert read("matmul_ms.dense")(run) is None     # an SpMV has no matmul
+    assert read("call_roofline.dense")(run) == \
+        read("call_roofline.sparse")(run)
+    run.trace = trace.Reduced(
+        window_s=0.0168, busy_s=0.0150,
+        op_seconds={"matmul": 0.0074, "block_map": 0.0026}, programs={},
+        gaps=[], spans={"bench.call": 1})
+    assert read("matmul_ms.dense")(run) == pytest.approx(7.4)
+    assert read("call_roofline.dense")(run) == pytest.approx(
+        100 * flops / 197e12 / 0.0150)
+    assert read("mfu.dense")(run) == pytest.approx(
+        100 * flops / 197e12 / 0.0168)
+    assert read("idle_share.dense")(run) == pytest.approx(
+        100 * (1 - 0.0150 / 0.0168))
+    run.trace = None
+    for name in ("matmul_ms.dense", "call_roofline.dense", "mfu.dense",
+                 "idle_share.dense"):
+        assert read(name)(run) is None

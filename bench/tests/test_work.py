@@ -39,10 +39,11 @@ def test_spmv_and_mlp_work():
     assert b == pytest.approx(4 * (1_465_138 + 2 * 21_013_830
                                    + 2 * 1_465_137))
     assert work.spmv_csr_flops(10) == 20
-    # qwen2-1.5b's gated MLP block over 16384 tokens: three products
-    f, b = work.swiglu_mlp_work(16384, 1536, 8960, "bfloat16")
-    assert f == 2 * 16384 * 1536 * 8960 * 3
-    assert b == 2 * (16384 * 1536 * 2 + 1536 * 8960 * 3)
+    # qwen2-1.5b's 28 gated MLP sublayers over 16384 tokens: three
+    # products a layer
+    f, b = work.swiglu_mlp_work(16384, 1536, 8960, 28, "bfloat16")
+    assert f == 2 * 16384 * 1536 * 8960 * 3 * 28
+    assert b == 2 * (16384 * 1536 * 2 + 28 * (1536 * 8960 * 3 + 1536))
     peak = common.peaks("TPU v5 lite")
     assert work.least_seconds(f, b, peak) == f / peak["bf16_flops_per_s"]
 

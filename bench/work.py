@@ -85,12 +85,13 @@ def spmv_csr_flops(nnz: int) -> float:
     return 2.0 * nnz
 
 
-def swiglu_mlp_work(t: int, d: int, f: int,
+def swiglu_mlp_work(t: int, d: int, f: int, layers: int,
                     dtype: str) -> Tuple[float, float]:
-    """``(silu(x @ gate) * (x @ up)) @ down`` with x (t, d), gate and up
-    (d, f), down (f, d): FLOPs of the three products, bytes of the
-    operands read once and the result written once."""
+    """``layers`` gated MLP sublayers over x (t, d), each with gate and
+    up (d, f), down (f, d) and a norm's scale (d,): FLOPs of the three
+    products of each, bytes of x and every layer's weights read once
+    and the result written once."""
     w = BYTES[dtype]
-    flops = 2.0 * t * d * f * 3
-    bytes_ = float((t * d + 3 * d * f + t * d) * w)
+    flops = 2.0 * t * d * f * 3 * layers
+    bytes_ = float((t * d + layers * (3 * d * f + d) + t * d) * w)
     return flops, bytes_
